@@ -30,33 +30,12 @@ import time
 
 import numpy as np
 
+from bench import compile_meter
 from benchmarks.common import codec_matrix, demo_elems, write_bench_json
 from repro.core import api, registry
 from repro.core.engine import CodagEngine, EngineConfig
 from repro.core.server import DecompressionService
 from repro.kernels import ops
-
-
-# --- backend-compile accounting -------------------------------------------
-# One process-wide jax.monitoring listener accumulating XLA backend-compile
-# durations; run() reads it by index range to attribute compile time to the
-# priming pass.  Registered once (jax has no unregister API).
-_compile_secs: list = []
-_listener_on = False
-
-
-def _ensure_compile_listener() -> None:
-    global _listener_on
-    if _listener_on:
-        return
-    import jax
-
-    def _cb(event, duration, **kw):
-        if "backend_compile" in event:
-            _compile_secs.append(duration)
-
-    jax.monitoring.register_event_duration_secs_listener(_cb)
-    _listener_on = True
 
 
 def build_pool(n_unique: int, kb_per_blob: int, chunk_bytes: int, seed: int):
@@ -140,18 +119,18 @@ def run(n_requests: int = 96, n_tenants: int = 6, n_unique: int = 24,
     engine = CodagEngine(EngineConfig())
 
     # priming pass on a throwaway service: jit caches are process-global,
-    # so this pays every window-bucket compilation ONCE while the
-    # monitoring listener attributes it — compile time becomes its own
-    # metric (serving/compile_ms) instead of polluting the cold-pass
+    # so this pays every window-bucket compilation ONCE while the compile
+    # meter (bench.compile_meter) attributes it — compile time becomes its
+    # own metric (serving/compile_ms) instead of polluting the cold-pass
     # latency percentiles.  With tuning.enable_compile_cache() active the
     # same number directly shows the persistent cache's cold-start win.
-    _ensure_compile_listener()
-    mark = len(_compile_secs)
+    meter = compile_meter.meter()
+    mark = meter.snapshot()
     with DecompressionService(engine, max_delay_ms=max_delay_ms,
                               idle_ms=max_delay_ms / 2,
                               cache_bytes=0) as svc_prime:
         _serve_trace(svc_prime, traces, blobs, arrays)
-    compile_ms = sum(_compile_secs[mark:]) * 1e3
+    compile_ms = compile_meter.delta(meter.snapshot(), mark)["compile_s"] * 1e3
 
     svc = DecompressionService(engine, max_delay_ms=max_delay_ms,
                                idle_ms=max_delay_ms / 2,
@@ -160,10 +139,11 @@ def run(n_requests: int = 96, n_tenants: int = 6, n_unique: int = 24,
     # compile-free after priming; pass 2 replays the same offered load in
     # steady state: shape buckets hit the jit cache and repeated blobs hit
     # the decoded-blob cache.
-    mark = len(_compile_secs)
+    mark = meter.snapshot()
     lat_cold, disp_cold, served_bytes, t_cold = _serve_trace(
         svc, traces, blobs, arrays)
-    residual_compile_ms = sum(_compile_secs[mark:]) * 1e3
+    residual_compile_ms = compile_meter.delta(
+        meter.snapshot(), mark)["compile_s"] * 1e3
     lat_steady, disp_steady, _, t_steady = _serve_trace(
         svc, traces, blobs, arrays)
     svc_stats = svc.stats()

@@ -75,6 +75,8 @@ import time
 ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path[:0] = [os.path.join(ROOT, "src"), ROOT]
 
+from bench import compile_meter  # noqa: E402
+
 MiB = 1 << 20
 SCAN_MB = {"rle_v1": 5, "rle_v2": 5, "tdeflate": 1, "dbp": 5, "lzss": 5,
            "huffman": 5, "bitpack": 32}
@@ -213,49 +215,18 @@ def _timed(fn):
     return out, time.perf_counter() - t0
 
 
-class CompileMeter:
-    """Backend compiles (persistent-cache loads included), their seconds
-    and the persistent-cache hits, counted by jax.monitoring listeners.
-    Use the one :func:`compile_meter` returns: jax has no way to
-    unregister a listener."""
-
-    def __init__(self) -> None:
-        import jax
-        self.totals = {"backend_compiles": 0, "cache_hits": 0,
-                       "compile_total_s": 0.0}
-        self._lock = threading.Lock()       # compiles run on many threads
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, secs, **kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            with self._lock:
-                self.totals["backend_compiles"] += 1
-                self.totals["compile_total_s"] += secs
-
-    def _event(self, event, **kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            with self._lock:
-                self.totals["cache_hits"] += 1
-
-    def run(self, name: str, fn, *args) -> None:
-        """Run one phase; log its wall time and the compiles it paid for."""
-        before = dict(self.totals)
-        t0 = time.perf_counter()
-        fn(*args)
-        log(name, phase_s=round(time.perf_counter() - t0, 3), **{
-            k: round(v - before[k], 3) for k, v in self.totals.items()})
-
-
-_meter: "CompileMeter | None" = None
-
-
-def compile_meter() -> CompileMeter:
-    """The process's one :class:`CompileMeter`."""
-    global _meter
-    if _meter is None:
-        _meter = CompileMeter()
-    return _meter
+def run_phase(name: str, fn, *args) -> None:
+    """Run one phase; log its wall time and the compiles it paid for, as
+    the process's one compile listener (``bench.compile_meter``) counts
+    them: backend compiles (persistent-cache loads included), their
+    seconds and the persistent-cache hits."""
+    meter = compile_meter.meter()
+    before = meter.snapshot()
+    t0 = time.perf_counter()
+    fn(*args)
+    log(name, phase_s=round(time.perf_counter() - t0, 3), **{
+        k: round(v, 3)
+        for k, v in compile_meter.delta(meter.snapshot(), before).items()})
 
 
 # --------------------------------------------------------------------------
@@ -275,16 +246,15 @@ def scan(columns: dict, cas: dict) -> None:
     from repro.kernels import ops
 
     dev0 = jax.devices()[0]
-    meter = compile_meter()
+    meter = compile_meter.meter()
     with jax.enable_x64(True), ops.count_dispatches() as calls:
         for codec, by_name in cas.items():
             arrs = [columns[codec][n] for n in by_name]
             cal = list(by_name.values())
-            before = dict(meter.totals)
+            before = meter.snapshot()
             outs, cold = _timed(
                 lambda: api.decompress_many(cal, device_out=True))
-            compile_s = meter.totals["compile_total_s"] - \
-                before["compile_total_s"]
+            compile_s = meter.snapshot()["compile_s"] - before["compile_s"]
             for name, arr, out in zip(by_name, arrs, outs):
                 _check(_same(out, arr), f"scan {codec}/{name} not bit-exact")
                 _check(out.devices() == {dev0},
@@ -297,7 +267,7 @@ def scan(columns: dict, cas: dict) -> None:
             want = [p for n, ca in by_name.items()
                     for p in _planes(columns[codec][n], ca)]
             plan = plan_mod.DecodePlan.build(blobs).stage()
-            before = dict(meter.totals)
+            before = meter.snapshot()
             with transfers.count_host_transfers() as moved, \
                     transfers.no_host_transfers():
                 outs, warm = _timed(plan.execute_device)
@@ -306,7 +276,7 @@ def scan(columns: dict, cas: dict) -> None:
                        f"scan {codec} guarded blob {i} not bit-exact")
             _check(moved["d2h"] == 0 and moved["h2d"] == 0,
                    f"scan {codec} guarded rerun moved data: {moved}")
-            _check(meter.totals["backend_compiles"]
+            _check(meter.snapshot()["backend_compiles"]
                    == before["backend_compiles"],
                    f"scan {codec} guarded rerun compiled")
             del outs
@@ -547,10 +517,9 @@ def main(argv=None) -> int:
     log("setup", device_kind=devs[0].device_kind, tuned_table_key=kind,
         tuned_table_row=has_row, devices=len(devs))
 
-    meter = compile_meter()
     t0 = time.perf_counter()
     if args.four_chips:
-        meter.run("four_chips", four_chips, args.seed)
+        run_phase("four_chips", four_chips, args.seed)
     else:
         from benchmarks import datasets
         full = datasets.build(max([RESTORE_MB] + [
@@ -559,12 +528,13 @@ def main(argv=None) -> int:
         columns = make_columns(full, SCAN_MB, args.seed)
         cas, svc_columns, svc_cas = encode_all(columns,
                                                os.cpu_count() or 1)
-        meter.run("scan", scan, columns, cas)
-        meter.run("service", service, svc_columns, svc_cas)
-        meter.run("restore", restore,
+        run_phase("scan", scan, columns, cas)
+        run_phase("service", service, svc_columns, svc_cas)
+        run_phase("restore", restore,
                   cut({k: full[k] for k in RESTORE_COLUMNS}, RESTORE_MB))
     log("total", seconds=round(time.perf_counter() - t0, 3),
-        **{k: round(v, 3) for k, v in meter.totals.items()})
+        **{k: round(v, 3)
+           for k, v in compile_meter.meter().snapshot().items()})
     print(json.dumps({"ok": True, "device": {
         "platform": devs[0].platform, "kind": devs[0].device_kind,
         "count": len(devs)}}))

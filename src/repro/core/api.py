@@ -16,6 +16,7 @@ import dataclasses
 from typing import List, Optional, Sequence, Union
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import encoders as enc
 from repro.core import format as fmt
@@ -192,12 +193,15 @@ def decompress_many(cas: Sequence[CompressedArray],
                 engine, epilogue=epilogue,
                 epilogue_operands=epilogue_operands, out_shardings=blob_sh)
         results = []
-        for ca, (s, n), sh in zip(cas, spans, per_array):
-            out = _combine_device(ca, outs[s:s + n], epilogue is not None)
-            if sh is not None and n > 1 and plan_mod.placeable(out.shape, sh):
-                import jax
-                out = jax.device_put(out, sh)
-            results.append(out)
+        with TraceAnnotation("codag.api.combine", plan=plan.plan_id,
+                             arrays=len(cas)):
+            for ca, (s, n), sh in zip(cas, spans, per_array):
+                out = _combine_device(ca, outs[s:s + n], epilogue is not None)
+                if (sh is not None and n > 1
+                        and plan_mod.placeable(out.shape, sh)):
+                    import jax
+                    out = jax.device_put(out, sh)
+                results.append(out)
         return results
     outs = plan_mod.decompress_blobs(flat, engine)
     return [_combine(ca, outs[s:s + n]) for ca, (s, n) in zip(cas, spans)]

@@ -39,6 +39,12 @@ and no single-device bottleneck.
     outs = plan.execute(engine)                     # host ndarrays
     devs = plan.execute_device(engine)              # device arrays, zero d2h
     shrd = plan.execute_sharded(mesh)               # rows decoded per device
+
+Each stage records a ``jax.profiler.TraceAnnotation`` (nothing until a
+profiler runs): ``codag.plan.build`` (stats ``groups``, ``chunks``,
+``blobs``), ``codag.plan.stage`` (``bytes`` uploaded by that call, 0 once
+staged) and one ``codag.plan.dispatch`` per group's jitted decode call
+(``codec``, ``width``, ``chunks``), each with the plan's ``plan`` id.
 """
 from __future__ import annotations
 
@@ -47,10 +53,12 @@ import contextlib
 import dataclasses
 import functools
 import hashlib
+import itertools
 import threading
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import format as fmt
 from repro.core import transfers
@@ -61,6 +69,15 @@ from repro.kernels import ops
 # sharing one plan) stays transfer-free without letting a pathological
 # caller grow device memory unboundedly.
 OPERAND_CACHE_SLOTS = 8
+
+# per-process plan ids: every span of one plan carries the same ``plan``
+_plan_ids = itertools.count()
+
+
+def _nbytes(tree) -> int:
+    """Bytes of a pytree's device arrays: what staging them uploaded."""
+    import jax
+    return sum(int(x.nbytes) for x in jax.tree.leaves(tree))
 
 
 def _default_engine(engine):
@@ -426,6 +443,8 @@ class DecodePlan:
 
     blobs: List[fmt.CompressedBlob]
     groups: List[PlanGroup]
+    # per-process id that every span of this plan carries (``build``)
+    plan_id: int = dataclasses.field(compare=False)
     # staged device inputs, lazily filled by stage(): group index -> device
     # pytree (placement key None = default device); plus staged per-blob
     # scatter index tables.
@@ -463,23 +482,28 @@ class DecodePlan:
         tuned-defaults table (``core.tuning``), falling back to 128.
         """
         blobs = list(blobs)
-        by_key: Dict[tuple, List[int]] = {}
-        for i, b in enumerate(blobs):
-            by_key.setdefault(fmt.group_key(b), []).append(i)
-        groups = []
-        for key, ids in by_key.items():   # insertion order = first occurrence
-            offsets, row = [], 0
-            for i in ids:
-                offsets.append(row)
-                row += blobs[i].num_chunks
-            merged = fmt.concat_blobs([blobs[i] for i in ids])
-            if bucket:
-                merged = fmt.pad_table_to_bucket(merged,
-                                                 cols_floor=bucket_floor)
-            groups.append(PlanGroup(
-                key=key, blob_ids=tuple(ids), row_offsets=tuple(offsets),
-                merged=merged, members=tuple(blobs[i] for i in ids)))
-        return cls(blobs=blobs, groups=groups)
+        plan_id = next(_plan_ids)
+        with TraceAnnotation("codag.plan.build", plan=plan_id,
+                             blobs=len(blobs)) as span:
+            by_key: Dict[tuple, List[int]] = {}
+            for i, b in enumerate(blobs):
+                by_key.setdefault(fmt.group_key(b), []).append(i)
+            groups = []
+            for key, ids in by_key.items():   # insertion order = first occurrence
+                offsets, row = [], 0
+                for i in ids:
+                    offsets.append(row)
+                    row += blobs[i].num_chunks
+                merged = fmt.concat_blobs([blobs[i] for i in ids])
+                if bucket:
+                    merged = fmt.pad_table_to_bucket(merged,
+                                                     cols_floor=bucket_floor)
+                groups.append(PlanGroup(
+                    key=key, blob_ids=tuple(ids), row_offsets=tuple(offsets),
+                    merged=merged, members=tuple(blobs[i] for i in ids)))
+            span.set_metadata(groups=len(groups),
+                              chunks=sum(g.num_chunks for g in groups))
+        return cls(blobs=blobs, groups=groups, plan_id=plan_id)
 
     @property
     def num_dispatches(self) -> int:
@@ -498,15 +522,21 @@ class DecodePlan:
         assignment stages per device).  After staging, the execute paths
         perform no host→device transfers — the decode→consume path can run
         under ``transfers.no_host_transfers()``."""
-        staged = self._staged.setdefault(placement, {})
-        scat = self._staged_scatter.setdefault(placement, {})
-        for gi, g in enumerate(self.groups):
-            if gi not in staged:
-                staged[gi] = ops.table_inputs(g.merged, placement)[0]
-            if gi not in scat:
-                scat[gi] = tuple(
-                    None if s is None else transfers.to_device(s, placement)
-                    for s in g.scatter)
+        with TraceAnnotation("codag.plan.stage", plan=self.plan_id) as span:
+            staged = self._staged.setdefault(placement, {})
+            scat = self._staged_scatter.setdefault(placement, {})
+            uploaded = []
+            for gi, g in enumerate(self.groups):
+                if gi not in staged:
+                    staged[gi] = ops.table_inputs(g.merged, placement)[0]
+                    uploaded.append(staged[gi])
+                if gi not in scat:
+                    scat[gi] = tuple(
+                        None if s is None
+                        else transfers.to_device(s, placement)
+                        for s in g.scatter)
+                    uploaded.append(scat[gi])
+            span.set_metadata(bytes=_nbytes(uploaded))
         return self
 
     def stage_sharded(self, mesh, axis: str) -> "DecodePlan":
@@ -516,39 +546,43 @@ class DecodePlan:
         and scatter indices replicate."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        key = (mesh, axis)
-        staged = self._staged.setdefault(key, {})
-        scat = self._staged_scatter.setdefault(key, {})
-        ndev = int(mesh.shape[axis])
-        for gi, g in enumerate(self.groups):
-            if gi in staged:
-                continue
-            n = g.merged.num_chunks
-            padded = fmt.pad_table_rows(g.merged, -(-n // ndev) * ndev)
-            dev_np = padded.to_device()
-            n_pad = padded.num_chunks
-            # per-chunk leaves shard over the axis; group-wide shared
-            # tables replicate.  Consult the codec's shared_extras — a
-            # shared table whose length happens to equal the padded chunk
-            # count must NOT be row-split (same disambiguation
-            # format.pad_table_rows / concat_blobs use).
-            from repro.core import registry
-            shared = set(registry.get(g.merged.codec).shared_extras)
-            perchunk = frozenset(
-                k for k, v in dev_np.items()
-                if k not in shared
-                and getattr(v, "ndim", 0) >= 1 and v.shape[0] == n_pad)
-            dev = {}
-            for k, v in dev_np.items():
-                nd = getattr(v, "ndim", 0)
-                spec = (P(axis, *([None] * (nd - 1))) if k in perchunk
-                        else P(*([None] * nd)))
-                dev[k] = transfers.to_device(v, NamedSharding(mesh, spec))
-            staged[gi] = (dev, perchunk)
-            scat[gi] = tuple(
-                None if s is None
-                else transfers.to_device(s, NamedSharding(mesh, P(None)))
-                for s in g.scatter)
+        with TraceAnnotation("codag.plan.stage", plan=self.plan_id) as span:
+            key = (mesh, axis)
+            staged = self._staged.setdefault(key, {})
+            scat = self._staged_scatter.setdefault(key, {})
+            ndev = int(mesh.shape[axis])
+            uploaded = []
+            for gi, g in enumerate(self.groups):
+                if gi in staged:
+                    continue
+                n = g.merged.num_chunks
+                padded = fmt.pad_table_rows(g.merged, -(-n // ndev) * ndev)
+                dev_np = padded.to_device()
+                n_pad = padded.num_chunks
+                # per-chunk leaves shard over the axis; group-wide shared
+                # tables replicate.  Consult the codec's shared_extras — a
+                # shared table whose length happens to equal the padded
+                # chunk count must NOT be row-split (same disambiguation
+                # format.pad_table_rows / concat_blobs use).
+                from repro.core import registry
+                shared = set(registry.get(g.merged.codec).shared_extras)
+                perchunk = frozenset(
+                    k for k, v in dev_np.items()
+                    if k not in shared
+                    and getattr(v, "ndim", 0) >= 1 and v.shape[0] == n_pad)
+                dev = {}
+                for k, v in dev_np.items():
+                    nd = getattr(v, "ndim", 0)
+                    spec = (P(axis, *([None] * (nd - 1))) if k in perchunk
+                            else P(*([None] * nd)))
+                    dev[k] = transfers.to_device(v, NamedSharding(mesh, spec))
+                staged[gi] = (dev, perchunk)
+                scat[gi] = tuple(
+                    None if s is None
+                    else transfers.to_device(s, NamedSharding(mesh, P(None)))
+                    for s in g.scatter)
+                uploaded += [dev, scat[gi]]
+            span.set_metadata(bytes=_nbytes(uploaded))
         return self
 
     def _stage_operands(self, operands: Optional[Dict[str, Any]],
@@ -600,11 +634,15 @@ class DecodePlan:
                                                device)[0]
         codec, width, chunk_elems, bits = self.groups[gi].key
         from repro.core import tuning
-        return dispatch(self_staged[gi], config=engine.config, codec=codec,
-                        width=width, chunk_elems=chunk_elems, bits=bits,
-                        epilogue=epilogue,
-                        tune=tuning.kernel_tune(codec, width,
-                                                engine.config.tune))
+        with TraceAnnotation("codag.plan.dispatch", plan=self.plan_id,
+                             codec=codec, width=width,
+                             chunks=self.groups[gi].num_chunks):
+            return dispatch(self_staged[gi], config=engine.config,
+                            codec=codec, width=width,
+                            chunk_elems=chunk_elems, bits=bits,
+                            epilogue=epilogue,
+                            tune=tuning.kernel_tune(codec, width,
+                                                    engine.config.tune))
 
     def _blob_meta(self, g: PlanGroup, transformed: bool,
                    places: Optional[List]) -> tuple:
@@ -661,12 +699,16 @@ class DecodePlan:
             if ops_extra:
                 dev = {**dev, **ops_extra}
             codec, width, chunk_elems, bits = g.key
-            group_outs = decode_scatter(
-                dev, list(self._staged_scatter[None][gi]),
-                cfg=engine.config, codec=codec, width=width,
-                chunk_elems=chunk_elems, bits=bits, epilogue=epilogue,
-                meta=self._blob_meta(g, epilogue is not None, places),
-                tune=tuning.kernel_tune(codec, width, engine.config.tune))
+            with TraceAnnotation("codag.plan.dispatch", plan=self.plan_id,
+                                 codec=codec, width=width,
+                                 chunks=g.num_chunks):
+                group_outs = decode_scatter(
+                    dev, list(self._staged_scatter[None][gi]),
+                    cfg=engine.config, codec=codec, width=width,
+                    chunk_elems=chunk_elems, bits=bits, epilogue=epilogue,
+                    meta=self._blob_meta(g, epilogue is not None, places),
+                    tune=tuning.kernel_tune(codec, width,
+                                            engine.config.tune))
             for bid, out in zip(g.blob_ids, group_outs):
                 outs[bid] = out
         return outs
@@ -699,13 +741,17 @@ class DecodePlan:
             if ops_extra:
                 dev = {**dev, **ops_extra}
             codec, width, chunk_elems, bits = g.key
-            group_outs = decode_sharded(
-                dev, list(self._staged_scatter[(mesh, axis)][gi]),
-                cfg=engine.config, codec=codec, width=width,
-                chunk_elems=chunk_elems, bits=bits, epilogue=epilogue,
-                meta=self._blob_meta(g, epilogue is not None, places),
-                mesh=mesh, axis=axis, perchunk=perchunk,
-                tune=tuning.kernel_tune(codec, width, engine.config.tune))
+            with TraceAnnotation("codag.plan.dispatch", plan=self.plan_id,
+                                 codec=codec, width=width,
+                                 chunks=g.num_chunks):
+                group_outs = decode_sharded(
+                    dev, list(self._staged_scatter[(mesh, axis)][gi]),
+                    cfg=engine.config, codec=codec, width=width,
+                    chunk_elems=chunk_elems, bits=bits, epilogue=epilogue,
+                    meta=self._blob_meta(g, epilogue is not None, places),
+                    mesh=mesh, axis=axis, perchunk=perchunk,
+                    tune=tuning.kernel_tune(codec, width,
+                                            engine.config.tune))
             for bid, out in zip(g.blob_ids, group_outs):
                 outs[bid] = out
         return outs

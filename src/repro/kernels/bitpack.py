@@ -82,8 +82,9 @@ def unpack_pallas(words: jnp.ndarray, *, bits: int, out_elems: int,
 
 def _body(inputs, consts, out_len, *, chunk_elems, width, bits):
     (words,) = inputs
-    out = unpack_tile(words, jnp.int32(0), chunk_elems, bits)
-    return out.astype(harness.DEV_DTYPE[width])
+    with jax.named_scope(harness.PHASE2):    # no Phase 1: fixed-width fields
+        out = unpack_tile(words, jnp.int32(0), chunk_elems, bits)
+        return out.astype(harness.DEV_DTYPE[width])
 
 
 def _body_scalar(inputs, consts, out_len, *, chunk_elems, width, bits):

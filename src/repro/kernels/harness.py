@@ -39,6 +39,14 @@ from jax.experimental import pallas as pl
 
 DEV_DTYPE = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32}
 
+# Stable ``jax.named_scope`` names on the decode programs: a profiler trace
+# names each device operation by the scopes it was traced under, so the
+# device time of a decode splits by stage whatever XLA calls the ops.
+# ``codag.decode.<codec>`` wraps a whole decode (``kernels.ops``).
+PHASE1 = "codag.phase1"        # the sequential parse loop
+PHASE2 = "codag.phase2"        # the expansion / command execution
+EPILOGUE = "codag.epilogue"    # Epilogue.apply
+
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
     """Pallas kernels run in interpret mode on the CPU backend and are
@@ -116,21 +124,24 @@ def two_phase_chunk(spec: TwoPhaseSpec, comp: jnp.ndarray, out_len_dyn,
         tabs = tuple(t.at[g].set(p[n]) for t, n in zip(tabs, names))
         return (pos + p["advance"], g + 1, cnt + p["length"], starts) + tabs
 
-    init = (jnp.int32(0), jnp.int32(0), jnp.int32(0),
-            jnp.full((MG,), out_len_max, jnp.int32),   # sentinel = out_len_max
-            *[jnp.zeros((MG,), f.dtype) for f in spec.fields])
-    final = lax.while_loop(cond, body, init)
+    with jax.named_scope(PHASE1):
+        init = (jnp.int32(0), jnp.int32(0), jnp.int32(0),
+                jnp.full((MG,), out_len_max, jnp.int32),  # sentinel = out_len_max
+                *[jnp.zeros((MG,), f.dtype) for f in spec.fields])
+        final = lax.while_loop(cond, body, init)
     starts, tabs = final[3], final[4:]
 
     # ---- Phase 2: all-lane expansion --------------------------------------
-    # lane->group map: scatter a 1 at every group start, prefix-sum.
-    marker = jnp.zeros((out_len_max + 1,), jnp.int32).at[starts].add(1)
-    grp = jnp.cumsum(marker[:out_len_max]) - 1
-    idx = jnp.arange(out_len_max, dtype=jnp.int32)
-    k = idx - jnp.take(starts, grp, mode="clip")
-    fields = {n: jnp.take(t, grp, mode="clip") for n, t in zip(names, tabs)}
-    out = spec.express(comp, fields, k, width)
-    return jnp.where(idx < out_len_dyn, out, 0).astype(dt)
+    with jax.named_scope(PHASE2):
+        # lane->group map: scatter a 1 at every group start, prefix-sum.
+        marker = jnp.zeros((out_len_max + 1,), jnp.int32).at[starts].add(1)
+        grp = jnp.cumsum(marker[:out_len_max]) - 1
+        idx = jnp.arange(out_len_max, dtype=jnp.int32)
+        k = idx - jnp.take(starts, grp, mode="clip")
+        fields = {n: jnp.take(t, grp, mode="clip")
+                  for n, t in zip(names, tabs)}
+        out = spec.express(comp, fields, k, width)
+        return jnp.where(idx < out_len_dyn, out, 0).astype(dt)
 
 
 def scalar_chunk(spec: TwoPhaseSpec, comp: jnp.ndarray, out_len_dyn,
@@ -226,19 +237,21 @@ class Epilogue:
     fn: Optional[Callable[..., jnp.ndarray]] = None
 
     def apply(self, out: jnp.ndarray, dev: Dict[str, Any]) -> jnp.ndarray:
-        if self.view_dtype is not None:
-            out = jax.lax.bitcast_convert_type(out, jnp.dtype(self.view_dtype))
-        if self.scale_key is not None or self.zero_key is not None:
-            out = out.astype(jnp.dtype(self.out_dtype or "float32"))
-            if self.zero_key is not None:
-                out = out - dev[self.zero_key].astype(out.dtype)
-            if self.scale_key is not None:
-                out = out * dev[self.scale_key].astype(out.dtype)
-        elif self.out_dtype is not None:
-            out = out.astype(jnp.dtype(self.out_dtype))
-        if self.fn is not None:
-            out = self.fn(out, dev)
-        return out
+        with jax.named_scope(EPILOGUE):
+            if self.view_dtype is not None:
+                out = jax.lax.bitcast_convert_type(
+                    out, jnp.dtype(self.view_dtype))
+            if self.scale_key is not None or self.zero_key is not None:
+                out = out.astype(jnp.dtype(self.out_dtype or "float32"))
+                if self.zero_key is not None:
+                    out = out - dev[self.zero_key].astype(out.dtype)
+                if self.scale_key is not None:
+                    out = out * dev[self.scale_key].astype(out.dtype)
+            elif self.out_dtype is not None:
+                out = out.astype(jnp.dtype(self.out_dtype))
+            if self.fn is not None:
+                out = self.fn(out, dev)
+            return out
 
 
 # --------------------------------------------------------------------------

@@ -142,36 +142,41 @@ def _body(inputs, consts, out_len, *, chunk_elems, width, bits, dbl_unroll=1):
         adv = jnp.where(is_m, 3, 1 + length * width)
         return pos + adv, g + 1, cnt + length, starts, kinds, dists, litoffs
 
-    init = (jnp.int32(0), jnp.int32(0), jnp.int32(0),
-            jnp.full((MT,), chunk_elems, jnp.int32),   # sentinel = chunk_elems
-            jnp.zeros((MT,), jnp.bool_),
-            jnp.zeros((MT,), jnp.int32),
-            jnp.zeros((MT,), jnp.int32))
-    _, _, _, starts, kinds, dists, litoffs = lax.while_loop(cond, body1, init)
+    with jax.named_scope(harness.PHASE1):
+        init = (jnp.int32(0), jnp.int32(0), jnp.int32(0),
+                jnp.full((MT,), chunk_elems, jnp.int32),  # sentinel = chunk_elems
+                jnp.zeros((MT,), jnp.bool_),
+                jnp.zeros((MT,), jnp.int32),
+                jnp.zeros((MT,), jnp.int32))
+        _, _, _, starts, kinds, dists, litoffs = lax.while_loop(
+            cond, body1, init)
 
     # ---- Phase 2: all-thread copy resolution ------------------------------
-    marker = jnp.zeros((chunk_elems + 1,), jnp.int32).at[starts].add(1)
-    grp = jnp.cumsum(marker[:chunk_elems]) - 1
-    idx = jnp.arange(chunk_elems, dtype=jnp.int32)
-    k = idx - jnp.take(starts, grp, mode="clip")
-    is_m = jnp.take(kinds, grp, mode="clip")
-    dist = jnp.take(dists, grp, mode="clip")
-    litbyte = jnp.take(litoffs, grp, mode="clip") + k * width
+    with jax.named_scope(harness.PHASE2):
+        marker = jnp.zeros((chunk_elems + 1,), jnp.int32).at[starts].add(1)
+        grp = jnp.cumsum(marker[:chunk_elems]) - 1
+        idx = jnp.arange(chunk_elems, dtype=jnp.int32)
+        k = idx - jnp.take(starts, grp, mode="clip")
+        is_m = jnp.take(kinds, grp, mode="clip")
+        dist = jnp.take(dists, grp, mode="clip")
+        litbyte = jnp.take(litoffs, grp, mode="clip") + k * width
 
-    # literal lanes are fixed points; match lanes point dist elements back.
-    # Chains strictly decrease, so log2 pointer-doubling rounds resolve
-    # every lane to its terminal literal lane (extra rounds are idempotent).
-    ptr = jnp.where(is_m, jnp.maximum(idx - dist, 0), idx)
-    rounds = max(1, (chunk_elems - 1).bit_length())
+        # literal lanes are fixed points; match lanes point dist elements
+        # back.  Chains strictly decrease, so log2 pointer-doubling rounds
+        # resolve every lane to its terminal literal lane (extra rounds are
+        # idempotent).
+        ptr = jnp.where(is_m, jnp.maximum(idx - dist, 0), idx)
+        rounds = max(1, (chunk_elems - 1).bit_length())
 
-    def dbl(r, p):
-        for _ in range(dbl_unroll):   # static unroll inside one loop step
-            p = jnp.take(p, p, mode="clip")
-        return p
+        def dbl(r, p):
+            for _ in range(dbl_unroll):   # static unroll inside one loop step
+                p = jnp.take(p, p, mode="clip")
+            return p
 
-    ptr = lax.fori_loop(0, -(-rounds // dbl_unroll), dbl, ptr)
-    vals = st.gather_values(comp, jnp.take(litbyte, ptr, mode="clip"), width)
-    return jnp.where(idx < out_len, vals, 0).astype(dt)
+        ptr = lax.fori_loop(0, -(-rounds // dbl_unroll), dbl, ptr)
+        vals = st.gather_values(comp, jnp.take(litbyte, ptr, mode="clip"),
+                                width)
+        return jnp.where(idx < out_len, vals, 0).astype(dt)
 
 
 def _body_scalar(inputs, consts, out_len, *, chunk_elems, width, bits):

@@ -39,10 +39,11 @@ BACKENDS = ("xla", "pallas", "oracle", "scalar")
 def _decode_impl(dev: Dict[str, Any], *, codec: str, width: int,
                  chunk_elems: int, backend: str, interpret: bool,
                  bits: int, epilogue, tune) -> jax.Array:
-    return harness.run(registry.get(codec).decode, dev, width=width,
-                       chunk_elems=chunk_elems, backend=backend,
-                       interpret=interpret, bits=bits, epilogue=epilogue,
-                       tune=tune)
+    with jax.named_scope(f"codag.decode.{codec}"):
+        return harness.run(registry.get(codec).decode, dev, width=width,
+                           chunk_elems=chunk_elems, backend=backend,
+                           interpret=interpret, bits=bits, epilogue=epilogue,
+                           tune=tune)
 
 
 # Dispatch observers (``count_dispatches``).  A plain list-of-lists instead

@@ -112,13 +112,15 @@ def decode_chunk(words: jnp.ndarray, lut_lsym: jnp.ndarray,
         return (bs, ci, out_cnt, lit_cnt, open_lit, lits,
                 done | is_eob, kinds, cmd_a, cmd_b)
 
-    init = (st.bitstream(words), jnp.int32(0), jnp.int32(0), jnp.int32(0),
-            jnp.bool_(False), jnp.zeros((out_len_max + CMD_WIN,), jnp.uint8),
-            jnp.bool_(False),
-            jnp.zeros((MC,), jnp.bool_),
-            jnp.zeros((MC,), jnp.int32),
-            jnp.zeros((MC,), jnp.int32))
-    s = lax.while_loop(cond, body, init)
+    with jax.named_scope(harness.PHASE1):
+        init = (st.bitstream(words), jnp.int32(0), jnp.int32(0),
+                jnp.int32(0), jnp.bool_(False),
+                jnp.zeros((out_len_max + CMD_WIN,), jnp.uint8),
+                jnp.bool_(False),
+                jnp.zeros((MC,), jnp.bool_),
+                jnp.zeros((MC,), jnp.int32),
+                jnp.zeros((MC,), jnp.int32))
+        s = lax.while_loop(cond, body, init)
     n_cmds, lits, kinds, cmd_a, cmd_b = s[1], s[5], s[7], s[8], s[9]
 
     # ---- Phase 2: execute commands (Table II writes) ----------------------
@@ -138,9 +140,10 @@ def decode_chunk(words: jnp.ndarray, lut_lsym: jnp.ndarray,
         out = jax.tree.map(lambda x, y: jnp.where(kind, x, y), out_m, out_l)
         return i + 1, out
 
-    _, out = lax.while_loop(cond2, body2, (jnp.int32(0), out0))
-    idx = jnp.arange(out_len_max, dtype=jnp.int32)
-    return jnp.where(idx < out_len_dyn, out.buf[:out_len_max], 0)
+    with jax.named_scope(harness.PHASE2):
+        _, out = lax.while_loop(cond2, body2, (jnp.int32(0), out0))
+        idx = jnp.arange(out_len_max, dtype=jnp.int32)
+        return jnp.where(idx < out_len_dyn, out.buf[:out_len_max], 0)
 
 
 def decode_chunk_scalar(words, lut_lsym, lut_lbits, lut_dsym, lut_dbits,

@@ -64,13 +64,19 @@ def test_restore_phase(data, capsys):
 def test_compile_meter_counts_a_fresh_compile(capsys):
     import jax
     import jax.numpy as jnp
-    meter = chip_smoke.compile_meter()
-    meter.run("probe", lambda: jax.jit(lambda x: x * 7 + 3)(
+    from bench import compile_meter
+    meter = compile_meter.meter()
+    before = meter.snapshot()
+    chip_smoke.run_phase("probe", lambda: jax.jit(lambda x: x * 7 + 3)(
         jnp.arange(5)).block_until_ready())
-    assert "probe phase_s=" in capsys.readouterr().out
-    assert meter.totals["backend_compiles"] >= 1
-    assert meter.totals["compile_total_s"] > 0
-    assert chip_smoke.compile_meter() is meter
+    line, = [ln for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("probe ")]
+    logged = dict(kv.split("=") for kv in line.split()[1:])
+    assert float(logged["phase_s"]) >= 0
+    grew = compile_meter.delta(meter.snapshot(), before)
+    assert grew["backend_compiles"] >= 1 and grew["compile_s"] > 0
+    assert int(logged["backend_compiles"]) == grew["backend_compiles"]
+    assert compile_meter.meter() is meter
 
 
 def test_main_refuses_without_tpu(capsys):
