@@ -30,7 +30,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import write_bench_json
-from repro.core import api, batch, transfers
+from repro.core import api, transfers
+from repro.core import plan as plan_mod
 from repro.core.engine import CodagEngine, EngineConfig
 from repro.kernels import dequant_matmul as dqm
 
@@ -82,7 +83,7 @@ def run(n_layers: int = 4, m: int = 128, k: int = 256, n: int = 256,
         return jax.block_until_ready(outs)
 
     epi, operands = dqm.weight_epilogue(ZERO_POINT)
-    plan = batch.BatchPlan.build([b for ca in cas for b in ca.blobs]).stage()
+    plan = plan_mod.DecodePlan.build([b for ca in cas for b in ca.blobs]).stage()
 
     def device_resident():
         dev_qs = plan.execute_device(engine, epilogue=epi,

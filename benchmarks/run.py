@@ -4,13 +4,10 @@
                                             [--all] [--smoke] [--out-dir D]
 
 Prints ``name,value,derived`` CSV rows:
-  throughput.py       -> Fig. 7 (absolute) + Fig. 8 (speedups)
-  ablations.py        -> §V-E (all-thread vs single-thread)
-                         §V-F (warp vs block provisioning + pool sweep)
   ratios.py           -> Table V (compression ratios, symbol lengths)
   roofline_report.py  -> §Roofline terms from the dry-run artifacts
   batched.py          -> launches-per-restore + throughput, batched vs
-                         per-blob decode (core.batch scheduler)
+                         per-blob decode (core.plan scheduler)
   serving.py          -> open-loop multi-tenant DecompressionService:
                          dispatch amplification, latency percentiles,
                          cache hit rate
@@ -39,9 +36,8 @@ from pathlib import Path
 
 def build_suites(args) -> dict:
     """{suite: (config_dict, thunk)} — the thunk returns CSV rows."""
-    from benchmarks import (ablations, autotune, batched, collectives,
-                            device_resident, ratios, roofline_report,
-                            serving, sharded, store, throughput)
+    from benchmarks import (autotune, batched, collectives, device_resident,
+                            ratios, roofline_report, serving, sharded, store)
     size_mb = 0.05 if args.smoke else args.size_mb
     batched_cfg = ({"n_arrays": 8, "kb_per_array": 8, "iters": 1}
                    if args.smoke else
@@ -75,14 +71,6 @@ def build_suites(args) -> dict:
                        {"steps": 24, "outer_every": 8, "batch": 2,
                         "seq": 64, "link_rtt_ms": 40.0, "topk_frac": 0.01})
     return {
-        "throughput": ({"size_mb": size_mb},
-                       lambda: throughput.run(size_mb)),
-        "ablation_decode": ({"size_mb": min(size_mb, 0.5)},
-                            lambda: ablations.run_decode_ablation(
-                                min(size_mb, 0.5))),
-        "ablation_unit": ({"size_mb": min(size_mb, 0.5)},
-                          lambda: ablations.run_unit_ablation(
-                              min(size_mb, 0.5))),
         "ratios": ({"size_mb": size_mb}, lambda: ratios.run(size_mb)),
         "roofline": ({}, roofline_report.run),
         "batched": (batched_cfg, lambda: batched.run(**batched_cfg)),
@@ -101,9 +89,8 @@ def main() -> None:
     ap.add_argument("--size-mb", type=float, default=0.25,
                 help="per-dataset size; 0.25 keeps the full suite ~10 min on CPU")
     ap.add_argument("--only", default=None,
-                    help="throughput|ablation_decode|ablation_unit|ratios|"
-                         "roofline|batched|serving|device|sharded|autotune|"
-                         "store|collectives")
+                    help="ratios|roofline|batched|serving|device|sharded|"
+                         "autotune|store|collectives")
     ap.add_argument("--all", action="store_true",
                     help="write one BENCH_<suite>.json per suite "
                          "(shared schema) into --out-dir")

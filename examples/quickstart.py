@@ -20,17 +20,6 @@ for codec in (fmt.RLE_V1, fmt.RLE_V2, fmt.TDEFLATE):
     print(f"{codec:9s}: {column.nbytes/1e6:6.2f} MB -> "
           f"{ca.compressed_bytes/1e6:6.3f} MB  (ratio {ca.ratio:.4f})")
 
-# provisioning strategies (the paper's core subject):
-for name, cfgE in {
-    "CODAG  warp-unit, all-thread  ": EngineConfig(unit="warp"),
-    "RAPIDS block-unit, single-thr.": EngineConfig(unit="block", n_units=8,
-                                                   all_thread=False),
-}.items():
-    eng = CodagEngine(cfgE)
-    out = api.decompress(api.compress(column, fmt.RLE_V2), eng)
-    assert np.array_equal(out, column)
-    print(f"engine [{name}] decode OK")
-
 # the Pallas kernel path: interpreted on the CPU backend, compiled by Mosaic
 # on a TPU (where the generic wrapper's blocks do not lower yet, so this
 # raises Mosaic's own error there instead of quietly interpreting):

@@ -4,8 +4,7 @@ Fails (exit 1) if any registered codec is missing from:
   * the fast-tier test matrix (tests/test_codecs.py parametrizes over
     ``registry.names()`` — verified here by importing its module-level
     matrix), or
-  * the bench-smoke matrices (benchmarks/batched.py, benchmarks/ablations.py),
-    or
+  * the bench-smoke matrix (benchmarks/batched.py), or
   * the golden conformance vectors (tests/vectors/<codec>.json — the
     committed encode/decode fixtures tests/test_conformance.py runs on
     every backend), or
@@ -66,10 +65,9 @@ def main() -> int:
     finally:
         sys.path.pop(0)
 
-    # bench-smoke matrices
-    from benchmarks import ablations, batched
-    for mod in (batched, ablations):
-        _diff(f"{mod.__name__}.codec_matrix()", set(mod.codec_matrix()))
+    # bench-smoke matrix
+    from benchmarks import batched
+    _diff("benchmarks.batched.codec_matrix()", set(batched.codec_matrix()))
 
     # golden conformance vectors: every codec must commit fixtures
     vec_dir = _ROOT / "tests" / "vectors"

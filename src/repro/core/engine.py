@@ -1,31 +1,25 @@
-"""CodagEngine — GPU-resource-provisioning strategies, transplanted.
+"""CodagEngine — the decode configuration every entry path carries.
 
 The paper's central claim is about *provisioning*: how many independent
 decompression streams the hardware scheduler can interleave.  The engine
-exposes that axis directly:
+always provisions CODAG's way: one independent stream per chunk (every
+chunk row of a fused table decodes side by side in one launch), with the
+all-thread two-phase decode inside each stream (§IV-D).
 
-  unit="warp"   (CODAG)  one chunk per independent stream — vmap across all
-                chunks / Pallas grid cell per chunk.  Maximal stream count.
-  unit="block"  (RAPIDS baseline, Fig. 1a) a fixed pool of ``n_units``
-                decompression units, each *serially* looping over its share
-                of chunks (lax.scan over serial batches of a vmapped pool).
-                This reproduces the baseline's few-streams provisioning.
+``EngineConfig`` picks only the backend:
 
-  all_thread=True   (CODAG §IV-D) vectorized two-phase decode — every lane
-                participates in decode+write.
-  all_thread=False  (§V-E ablation) single-thread decoding: one element per
-                loop step.
-
+  backend="xla"     the codec bodies compiled by XLA: the decode path on
+                    every platform, the TPU included.
   backend="pallas"  the Pallas kernels: interpreted on the CPU backend,
-                compiled by Mosaic on the TPU (``harness.resolve_interpret``);
-  backend="xla"     same decode bodies compiled by XLA (the decode path on
-                every platform, the TPU included).
+                    compiled by Mosaic on the TPU (``harness.resolve_interpret``).
+  backend="oracle"  the sequential references the other two are checked
+                    against.
 
 The engine is a *configuration* wrapper: every decode it issues lowers
 through the unified plan IR (``core.plan.dispatch`` is the one
 ``ops.decode`` site; the convenience round trips build one-blob
-``DecodePlan``s), so the engine, the batch scheduler, the public API, and
-the serving loop all execute the same pipeline.
+``DecodePlan``s), so the engine, the public API, and the serving loop all
+execute the same pipeline.
 """
 from __future__ import annotations
 
@@ -43,9 +37,6 @@ from repro.kernels import ops
 
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
-    unit: str = "warp"          # "warp" (CODAG) | "block" (RAPIDS-like)
-    n_units: int = 8            # decompression-unit pool size for "block"
-    all_thread: bool = True     # False = §V-E single-thread decoding
     backend: str = "xla"        # "xla" | "pallas" | "oracle"
     interpret: Optional[bool] = None  # None: interpret on CPU only
     # explicit kernel-knob overrides ((name, value), ...) — merged over the
@@ -63,7 +54,7 @@ class CodagEngine:
         """Decode to (num_chunks, chunk_elems); jit-compatible.
 
         Lowers straight to the plan IR's dispatch stage (the repo's one
-        ``ops.decode`` call site) under this engine's provisioning config.
+        ``ops.decode`` call site) under this engine's config.
         ``epilogue``: optional ``kernels.harness.Epilogue`` fused into the
         dispatch (cast/widen/dequant before the matrix reaches a consumer).
         """

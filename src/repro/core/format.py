@@ -445,7 +445,7 @@ def reassemble_device(blob: CompressedBlob, chunks_out, *,
     without leaving the device (jit-compatible; bit-exact vs the host path).
 
     ``indices``: optional pre-staged gather from :func:`reassemble_indices`
-    (e.g. carried by a ``BatchPlan``); by default it is derived here from
+    (e.g. carried by a ``DecodePlan``); by default it is derived here from
     the blob's host metadata.
     """
     if indices is None:

@@ -1,11 +1,11 @@
 """DecodePlan — the unified decode-pipeline IR every entry path lowers to.
 
 CODAG's throughput story is provisioning: a launch must carry as many
-independent decode streams as the hardware exposes.  The repo grew four
+independent decode streams as the hardware exposes.  The repo grew three
 public decode entry paths (``engine.decompress*``, ``api.decompress_many``,
-``batch.BatchPlan``, ``server.DecompressionService``) that each
-re-implemented the same group → stage → dispatch → reassemble sequence.
-This module is that sequence, written once, as an inspectable IR:
+``server.DecompressionService``) that each re-implemented the same
+group → stage → dispatch → reassemble sequence.  This module is that
+sequence, written once, as an inspectable IR:
 
     parse/group  — partition blobs by ``(codec, width, chunk_elems, bits)``
                    and fuse each group's chunk tables into one flat stream
@@ -15,8 +15,8 @@ This module is that sequence, written once, as an inspectable IR:
                    operands through the ``transfers.to_device`` funnel
                    (once; staged plans re-execute transfer-free).
     dispatch     — ONE ``ops.decode`` lowering site for the whole repo
-                   (:func:`dispatch`), covering both the warp (CODAG) and
-                   block (RAPIDS-ablation) provisioning units.
+                   (:func:`dispatch`): one independent stream per chunk
+                   row, all rows in one launch.
     reassemble   — per-blob row-range scatter back to original arrays
                    (``format.reassemble_rows_device``), on device.
     epilogue     — optional fused consumer transform
@@ -123,21 +123,17 @@ def dispatch(dev: Dict[str, Any], *, config, codec: str, width: int,
              chunk_elems: int, bits: int = 0, epilogue=None, tune=None):
     """Stage 3 of the pipeline: lower one fused chunk table to ``ops.decode``.
 
-    ``config`` is an ``engine.EngineConfig`` (hashable, jit-static): it
-    selects the provisioning unit — ``warp`` issues the whole table as one
-    launch of independent streams (CODAG); ``block`` reproduces the
-    fixed-pool RAPIDS baseline by scanning serial batches of ``n_units``
-    streams.  This function is the only ``ops.decode`` call site outside
-    the kernels layer — every entry path's decode lowers through it.
+    The whole table goes out as one launch of independent streams, one per
+    chunk row (CODAG's provisioning).  ``config`` is an
+    ``engine.EngineConfig`` (hashable, jit-static) and picks the backend.
+    This function is the only ``ops.decode`` call site outside the kernels
+    layer — every entry path's decode lowers through it.
 
     ``tune``: the static kernel-knob tuple (``core.tuning.kernel_tune``).
     ``None`` resolves tuned defaults merged with ``config.tune`` here —
     only safe outside an outer jit trace; the plan's jitted executors
     resolve it eagerly and pass it through as a static argument.
     """
-    import jax
-    import jax.numpy as jnp
-
     if tune is None:
         from repro.core import tuning
         tune = tuning.kernel_tune(codec, width, getattr(config, "tune", ()))
@@ -146,50 +142,13 @@ def dispatch(dev: Dict[str, Any], *, config, codec: str, width: int,
         if _lowered:
             rec = {"num_chunks": int(dev["comp"].shape[0]), "codec": codec,
                    "width": width, "chunk_elems": chunk_elems, "bits": bits,
-                   "unit": config.unit, "backend": config.backend}
+                   "backend": config.backend}
             for calls in _lowered:
                 calls.append(dict(rec))
 
-    backend = config.backend if config.all_thread else "scalar"
-    if config.unit == "warp":
-        return ops.decode(dev, codec=codec, width=width,
-                          chunk_elems=chunk_elems, backend=backend,
-                          interpret=config.interpret, bits=bits,
-                          epilogue=epilogue, tune=tune)
-    # "block": fixed pool of n_units streams; serial over chunk batches.
-    n_chunks = dev["comp"].shape[0]
-    nu = min(config.n_units, n_chunks)
-    n_serial = (n_chunks + nu - 1) // nu
-    pad = n_serial * nu - n_chunks
-
-    def pad0(x):
-        # shared tables (e.g. bitpack bits) and scalar epilogue
-        # operands replicate across serial batches unchanged
-        if x.ndim == 0 or x.shape[0] != n_chunks:
-            return x
-        return jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1))
-
-    devp = {k: pad0(v) for k, v in dev.items()}
-    # out_lens of padding rows are 0 -> decode loops exit immediately.
-    # Only per-chunk tables are scanned over; shared tables / scalar
-    # epilogue operands have no n_chunks leading dim and must replicate
-    # to every serial batch via closure (lax.scan requires every
-    # scanned leaf to share the leading dim).
-    scanned = {k: v.reshape((n_serial, nu) + v.shape[1:])
-               for k, v in devp.items()
-               if v.ndim and v.shape[0] == n_serial * nu}
-    shared = {k: v for k, v in devp.items() if k not in scanned}
-
-    def step(carry, batch):
-        out = ops.decode({**batch, **shared}, codec=codec, width=width,
-                         chunk_elems=chunk_elems, backend=backend,
-                         interpret=config.interpret, bits=bits,
-                         epilogue=epilogue, tune=tune)
-        return carry, out
-
-    _, outs = jax.lax.scan(step, 0, scanned)
-    out = outs.reshape((n_serial * nu,) + outs.shape[2:])
-    return out[:n_chunks]
+    return ops.decode(dev, codec=codec, width=width, chunk_elems=chunk_elems,
+                      backend=config.backend, interpret=config.interpret,
+                      bits=bits, epilogue=epilogue, tune=tune)
 
 
 # --------------------------------------------------------------------------
@@ -437,8 +396,8 @@ class DecodePlan:
     ``build`` is the parse/group stage; the ``execute*`` methods run the
     remaining stages on a single device, a caller-chosen device, or a
     device mesh.  Every entry path in the repo — ``api.decompress_many``,
-    ``engine.decompress*``, ``batch.BatchPlan`` (an alias of this class),
-    and the ``DecompressionService`` window loop — lowers to this IR.
+    ``engine.decompress*`` and the ``DecompressionService`` window loop —
+    lowers to this IR.
     """
 
     blobs: List[fmt.CompressedBlob]

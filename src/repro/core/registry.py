@@ -7,8 +7,8 @@ module is the single place where a codec declares everything the rest of the
 system needs:
 
   * ``encode``        — the host-side encoder (array -> ``CompressedBlob``)
-  * ``decode``        — a ``kernels.harness.DecodeSpec`` covering the four
-                        backends (xla / pallas / scalar / oracle)
+  * ``decode``        — a ``kernels.harness.DecodeSpec`` covering the three
+                        backends (xla / pallas / oracle)
   * ``needs_words``   — whether the device layout carries a uint32 word view
                         (bit-oriented codecs)
   * ``shared_extras`` — extras keys shared across blobs of a batch group

@@ -1,6 +1,6 @@
 """Async decompression service with adaptive micro-batching.
 
-The batch scheduler (``core.batch``) fuses blobs that arrive *together* in
+The decode plan (``core.plan``) fuses blobs that arrive *together* in
 one call.  A serving workload does not arrive together: requests trickle in
 per-tensor from many producers, and decoding each on arrival reproduces the
 few-streams provisioning pathology of paper Fig. 1a launch by launch — every
@@ -343,7 +343,7 @@ class DecompressionService:
                       device_out: bool = False) -> List:
         """Blocking batch decode of ``CompressedArray``s.  All plane blobs of
         all arrays enter one window atomically, so the call costs exactly one
-        dispatch per group key (same accounting as ``batch.BatchPlan``)."""
+        dispatch per group key (same accounting as ``plan.DecodePlan``)."""
         flat = [b for ca in cas for b in ca.blobs]
         futs = self.submit_many(flat, device_out=device_out)
         outs = [f.result() for f in futs]

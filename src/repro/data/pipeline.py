@@ -22,9 +22,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import batch as cbatch
 from repro.core import encoders as enc
 from repro.core import format as fmt
+from repro.core import plan as cplan
 from repro.core import store as blobstore
 from repro.core.engine import CodagEngine, EngineConfig
 from repro.core.server import DecompressionService
@@ -162,7 +162,6 @@ class CompressedTokenStore:
             # the int32 widening is a device op too; re-commit under the
             # data-axis sharding so the yielded shard carries it verbatim
             # (ragged tail shards that cannot satisfy the spec stay put)
-            from repro.core import plan as cplan
             cast = lambda a: (jax.device_put(a.astype(jnp.int32), out_sh)
                               if cplan.placeable(a.shape, out_sh)
                               else a.astype(jnp.int32))
@@ -171,7 +170,7 @@ class CompressedTokenStore:
         else:
             cast = lambda a: a.astype(np.int32)
         for blobs in self._blob_windows(max(1, window)):
-            for out in cbatch.decompress_blobs(
+            for out in cplan.decompress_blobs(
                     blobs, engine,
                     device_out=device_out, mesh=mesh, out_shardings=out_sh):
                 yield cast(out)

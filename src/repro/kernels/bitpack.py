@@ -20,7 +20,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.experimental import pallas as pl
 
 from repro.core import encoders as enc
@@ -87,17 +86,6 @@ def _body(inputs, consts, out_len, *, chunk_elems, width, bits):
         return out.astype(harness.DEV_DTYPE[width])
 
 
-def _body_scalar(inputs, consts, out_len, *, chunk_elems, width, bits):
-    """§V-E single-thread baseline: one element unpacked per loop step."""
-    (words,) = inputs
-    dt = harness.DEV_DTYPE[width]
-
-    def step(i, buf):
-        return buf.at[i].set(unpack_tile(words, i, 1, bits)[0].astype(dt))
-
-    return lax.fori_loop(0, out_len, step, jnp.zeros((chunk_elems,), dt))
-
-
 def _body_oracle(inputs, consts, out_len, *, chunk_elems, width, bits):
     (words,) = inputs
     return ref.unpack_bits(words, chunk_elems, bits).astype(
@@ -127,7 +115,6 @@ CODEC = registry.register(registry.Codec(
     encode=enc.compress_bitpack,
     decode=harness.DecodeSpec(
         body=_body,
-        body_scalar=_body_scalar,
         body_oracle=_body_oracle,
         chunk_inputs=harness.words_inputs,
         pallas_override=_pallas,

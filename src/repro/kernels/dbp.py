@@ -1,7 +1,7 @@
 """dbp codec plugin — frame-of-reference delta + bitpack (NEW codec).
 
 The extensibility proof for the codec-plugin framework: everything dbp
-needs — encoder, all four decode backends, batch-scheduler grouping,
+needs — encoder, all three decode backends, batch-scheduler grouping,
 checkpoint restore, pipeline decode, bench/test matrices — comes from this
 one module plus its ``registry.register`` call.  Nothing outside the plugin
 names the codec.

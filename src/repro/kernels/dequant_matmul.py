@@ -123,16 +123,16 @@ def decompress_dequant_matmul(x: jnp.ndarray, ca, s: jnp.ndarray, *,
     group, epilogue fused in), then consumed by the fused dequant matmul —
     no uint intermediate, no host round trip.
 
-    The staged ``BatchPlan`` (fused tables + scatter + operands, uploaded
+    The staged ``DecodePlan`` (fused tables + scatter + operands, uploaded
     once) is cached on ``ca``, so repeat calls over the same compressed
     weights — the serving steady state — perform no host transfers at all.
     """
-    from repro.core import batch as batch_mod
+    from repro.core import plan as plan_mod
     from repro.core.engine import CodagEngine, EngineConfig
     cached = getattr(ca, "_dqm_plan", None)
     if cached is None or cached[2] != zero_point:
         epi, operands = weight_epilogue(zero_point)
-        plan = batch_mod.BatchPlan.build(list(ca.blobs)).stage()
+        plan = plan_mod.DecodePlan.build(list(ca.blobs)).stage()
         cached = (plan, (epi, operands), zero_point)
         ca._dqm_plan = cached
     plan, (epi, operands), _ = cached

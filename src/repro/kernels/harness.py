@@ -11,8 +11,6 @@ author should NOT have to write:
     fields, and let every VPU lane evaluate the codec's value expression
     independently (Table II's vectorized ``write_run``; literals ride the
     shared multi-byte gather ``streams.gather_values``).
-  * the §V-E ablation     — a generic single-thread driver emitting one
-    element per loop step from the same parse/express hooks.
   * a group-serial oracle — one group per step, vector-blend write: the
     paper-faithful sequential reference, free for any two-phase codec.
   * ONE ``pallas_call``   — the generic chunk-per-grid-cell wrapper: every
@@ -21,7 +19,7 @@ author should NOT have to write:
     provisioning), broadcast constants get index-map ``(0, 0)``.
 
 A two-phase codec (rle_v1, rle_v2, dbp) supplies a ``TwoPhaseSpec`` — a
-header parse and a value expression — and gets all four backends.  Codecs
+header parse and a value expression — and gets all three backends.  Codecs
 whose Phase 2 is not lane-independent (the LZ codecs tdeflate and lzss,
 whose back-references read earlier output) or that need no Phase 1 at all
 (bitpack) plug custom chunk bodies into the same ``DecodeSpec`` interface
@@ -95,9 +93,8 @@ class TwoPhaseSpec:
     returns a dict with ``"length"`` (elements this group expands to),
     ``"advance"`` (total group bytes, header + payload), and one entry per
     declared field.  ``express(comp, fields, k, width)`` computes element
-    ``k`` of a group from its gathered fields — it must be shape-polymorphic
-    (scalar ``k`` in the single-thread driver, a lane vector in Phase 2 and
-    the group-serial oracle) and return uint32.
+    ``k`` of a group from its gathered fields — ``k`` is a lane vector
+    (Phase 2 and the group-serial oracle) — and returns uint32.
     """
 
     fields: Tuple[Field, ...]
@@ -208,36 +205,6 @@ def resolve_backrefs(starts: jnp.ndarray, is_match: jnp.ndarray,
 
     ptr = lax.fori_loop(0, -(-rounds // unroll), dbl, ptr)
     return lane_take(src, ptr)
-
-
-def scalar_chunk(spec: TwoPhaseSpec, comp: jnp.ndarray, out_len_dyn,
-                 out_len_max: int, width: int) -> jnp.ndarray:
-    """§V-E baseline: a single decode 'thread' emits one element per step —
-    the serial-latency ablation, generic over any TwoPhaseSpec."""
-    dt = DEV_DTYPE[width]
-    names = [f.name for f in spec.fields]
-
-    def cond(s):
-        return s[1] < out_len_dyn
-
-    def body(s):
-        pos, cnt, k, rem, buf = s[0], s[1], s[2], s[3], s[4]
-        cur = dict(zip(names, s[5:]))
-        need = rem == 0
-        p = spec.parse(comp, pos, width)
-        cur = {n: jnp.where(need, p[n], cur[n]) for n in names}
-        rem = jnp.where(need, p["length"], rem)
-        k = jnp.where(need, 0, k)
-        pos = jnp.where(need, pos + p["advance"], pos)
-        v = spec.express(comp, cur, k, width)
-        buf = buf.at[cnt].set(v.astype(dt))
-        return (pos, cnt + 1, k + 1, rem - 1, buf) + tuple(cur[n] for n in names)
-
-    init = (jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.int32(0),
-            jnp.zeros((out_len_max,), dt),
-            *[jnp.zeros((), f.dtype) for f in spec.fields])
-    s = lax.while_loop(cond, body, init)
-    return s[4]
 
 
 def group_serial_chunk(spec: TwoPhaseSpec, comp: jnp.ndarray, out_len_dyn,
@@ -368,7 +335,6 @@ class DecodeSpec:
     """
 
     body: BodyFn
-    body_scalar: Optional[BodyFn] = None      # §V-E driver; falls back to body
     body_oracle: Optional[BodyFn] = None      # sequential ref; falls back to body
     chunk_inputs: Callable[[Dict[str, Any]], Tuple[jnp.ndarray, ...]] = _default_inputs
     consts: Callable[[], Tuple[jnp.ndarray, ...]] = tuple
@@ -386,7 +352,7 @@ class DecodeSpec:
     def from_two_phase(cls, spec: TwoPhaseSpec,
                        oracle: Optional[Callable[..., jnp.ndarray]] = None,
                        ) -> "DecodeSpec":
-        """All four backends from a parse + express pair.
+        """All three backends from a parse + express pair.
 
         ``oracle`` optionally swaps in a handwritten sequential reference
         (signature ``(comp, out_len_dyn, out_len_max, width)``); by default
@@ -395,14 +361,11 @@ class DecodeSpec:
         def body(inputs, consts, out_len, *, chunk_elems, width, bits):
             return two_phase_chunk(spec, inputs[0], out_len, chunk_elems, width)
 
-        def body_scalar(inputs, consts, out_len, *, chunk_elems, width, bits):
-            return scalar_chunk(spec, inputs[0], out_len, chunk_elems, width)
-
         def body_oracle(inputs, consts, out_len, *, chunk_elems, width, bits):
             fn = oracle or functools.partial(group_serial_chunk, spec)
             return fn(inputs[0], out_len, chunk_elems, width)
 
-        return cls(body=body, body_scalar=body_scalar, body_oracle=body_oracle)
+        return cls(body=body, body_oracle=body_oracle)
 
 
 def run(spec: DecodeSpec, dev: Dict[str, Any], *, width: int,
@@ -418,8 +381,8 @@ def run(spec: DecodeSpec, dev: Dict[str, Any], *, width: int,
     ``tune``: sorted ``((knob, value), ...)`` of kernel knobs — the generic
     wrapper's ``num_stages`` plus any codec ``Tunable``s — resolved by the
     caller (``core.tuning.kernel_tune``).  Static: new values are new
-    compilations.  Kernel knobs shape only the Pallas launch; the XLA /
-    scalar / oracle backends ignore them (the decoded values are knob-
+    compilations.  Kernel knobs shape only the Pallas launch; the XLA and
+    oracle backends ignore them (the decoded values are knob-
     independent by the conformance gate)."""
     inputs = spec.chunk_inputs(dev)
     consts = tuple(spec.consts())
@@ -432,7 +395,6 @@ def run(spec: DecodeSpec, dev: Dict[str, Any], *, width: int,
                      interpret=interpret, tune=tune)
         return epilogue.apply(out, dev) if epilogue is not None else out
     body = {"xla": spec.body,
-            "scalar": spec.body_scalar or spec.body,
             "oracle": spec.body_oracle or spec.body}[backend]
     n_in = len(inputs)
 
@@ -471,7 +433,7 @@ def _generic_pallas(body: BodyFn, inputs, consts, out_lens, *,
     pad = -n % num_stages
     if pad:
         # zero rows decode to nothing (out_lens 0 -> every body exits
-        # immediately), same convention as the engine's block mode
+        # immediately), the same convention as the plan's row padding
         inputs = tuple(jnp.pad(a, ((0, pad), (0, 0))) for a in inputs)
         out_lens = jnp.pad(out_lens, (0, pad))
     n_pad = n + pad

@@ -33,11 +33,9 @@ the only table a real container would ship — counted in ``ratio``); the
 flat 4096-entry decode LUTs (``lut_hsym`` / ``lut_hbits``) are derived from
 it at encode time and ride the device pytree like tdeflate's.
 
-Backends cross-check the index from two directions: the scalar §V-E body
-deliberately IGNORES the gap array (beyond entry 0) and decodes the payload
-as one sequential bit stream — the encoder's segment offsets must agree
-with the payload bit-for-bit or the suites fail; the oracle walks segment
-by segment trusting both the offsets and the count bytes.
+The oracle walks segment by segment, trusting both the offsets and the
+count bytes; the golden vectors and the round trips against the original
+data check the encoder's offsets.
 """
 from __future__ import annotations
 
@@ -177,29 +175,6 @@ def _body(inputs, consts, out_len, *, chunk_elems, width, bits, sub_unroll=1):
         return out.astype(harness.DEV_DTYPE[width])
 
 
-def _body_scalar(inputs, consts, out_len, *, chunk_elems, width, bits):
-    """§V-E single-thread baseline: one symbol per step, sequentially from
-    the payload start — the gap array (beyond entry 0) is deliberately
-    unused, so this body cross-checks the encoder's segment offsets."""
-    comp, words, lut_sym, lut_bits = inputs
-    dt = harness.DEV_DTYPE[width]
-    pos0 = st.read_value_at(comp, 0, 4).astype(jnp.int32)   # = gap table bits
-
-    def cond(s):
-        return s[1] < out_len
-
-    def body(s):
-        pos, i, buf = s
-        v = st.peek_bits(st.BitStream(words=words, pos=pos), enc.MAX_CODE_BITS)
-        sym = jnp.take(lut_sym, v.astype(jnp.int32), mode="clip")
-        nb = jnp.take(lut_bits, v.astype(jnp.int32), mode="clip")
-        return pos + nb, i + 1, buf.at[i].set(sym.astype(dt))
-
-    s = lax.while_loop(cond, body, (pos0, jnp.int32(0),
-                                    jnp.zeros((chunk_elems,), dt)))
-    return s[2]
-
-
 def _body_oracle(inputs, consts, out_len, *, chunk_elems, width, bits):
     """Sequential reference: segment by segment through the gap table, each
     segment decoded serially from its own bit offset and blend-written at
@@ -282,7 +257,6 @@ CODEC = registry.register(registry.Codec(
     encode=compress_huffman,
     decode=harness.DecodeSpec(
         body=_body,
-        body_scalar=_body_scalar,
         body_oracle=_body_oracle,
         chunk_inputs=_chunk_inputs,
         pallas_override=_pallas,

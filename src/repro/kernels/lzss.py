@@ -28,9 +28,8 @@ an offset prefix sum and Phase 2 is an all-thread copy:
       every lane's value from the compressed stream.  No serial command
       loop: Phase 2 is all-thread, like the paper's RLE expansion.
 
-The §V-E scalar body decodes one element per step with a scalar
-back-reference cursor; the oracle is the classic serial token walk using
-the Table II ``memcpy`` (overlap-safe circular window).
+The oracle is the classic serial token walk using the Table II ``memcpy``
+(overlap-safe circular window).
 """
 from __future__ import annotations
 
@@ -160,41 +159,6 @@ def _body(inputs, consts, out_len, *, chunk_elems, width, bits, dbl_unroll=1):
         return jnp.where(idx < out_len, vals, 0).astype(dt)
 
 
-def _body_scalar(inputs, consts, out_len, *, chunk_elems, width, bits):
-    """§V-E single-thread baseline: one element per step; matches proceed
-    element-by-element through a scalar back-reference cursor."""
-    (comp,) = inputs
-    dt = harness.DEV_DTYPE[width]
-
-    def cond(s):
-        return s[1] < out_len
-
-    def body(s):
-        pos, i, rem, is_m, src, buf = s
-        need = rem == 0
-        c = st.read_byte_at(comp, pos)
-        new_m = c >= 128
-        new_len = jnp.where(new_m, c - 128 + MIN_MATCH, c + 1)
-        new_dist = st.read_value_at(comp, pos + 1, 2).astype(jnp.int32)
-        # src is an element index for matches, a byte offset for literals
-        new_src = jnp.where(new_m, i - new_dist, pos + 1)
-        is_m = jnp.where(need, new_m, is_m)
-        rem = jnp.where(need, new_len, rem)
-        src = jnp.where(need, new_src, src)
-        pos = jnp.where(need,
-                        pos + jnp.where(new_m, 3, 1 + new_len * width), pos)
-        v_lit = st.gather_values(comp, src, width)
-        v_m = jnp.take(buf, jnp.maximum(src, 0), mode="clip").astype(jnp.uint32)
-        buf = buf.at[i].set(jnp.where(is_m, v_m, v_lit).astype(dt))
-        return (pos, i + 1, rem - 1, is_m,
-                src + jnp.where(is_m, 1, width), buf)
-
-    init = (jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.bool_(False),
-            jnp.int32(0), jnp.zeros((chunk_elems,), dt))
-    s = lax.while_loop(cond, body, init)
-    return s[5]
-
-
 def _body_oracle(inputs, consts, out_len, *, chunk_elems, width, bits):
     """Serial token walk with the Table II primitives: blend-write literal
     runs, overlap-safe circular-window ``memcpy`` for matches."""
@@ -267,7 +231,6 @@ CODEC = registry.register(registry.Codec(
     encode=compress_lzss,
     decode=harness.DecodeSpec(
         body=_body,
-        body_scalar=_body_scalar,
         body_oracle=_body_oracle,
         pallas_override=_pallas,
         tunables=(harness.Tunable("dbl_unroll", (1, 2, 4), 1),),

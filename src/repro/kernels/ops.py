@@ -2,14 +2,13 @@
 
 Backends:
   "xla"    — the per-codec chunk bodies vmapped across chunks and compiled
-             by XLA (used on CPU and as the production non-Pallas path).
+             by XLA: the decode path on every platform, the TPU included.
   "pallas" — pl.pallas_call kernels (``interpret=None`` resolves to the
              interpreter on the CPU backend and to Mosaic elsewhere).
   "oracle" — the sequential stream-based reference decoders.
-  "scalar" — the single-thread-decoding §V-E ablation baselines.
 
 Dispatch is pure registry lookup: ``registry.get(codec).decode`` is a
-``kernels.harness.DecodeSpec`` carrying all four backend bodies, so this
+``kernels.harness.DecodeSpec`` carrying all three backends' bodies, so this
 module names no codec.  All entry points take the device pytree from
 ``CompressedBlob.to_device()`` plus the blob's static metadata, and return
 (num_chunks, chunk_elems) in the codec's device dtype.
@@ -30,7 +29,7 @@ from repro.kernels import harness
 from repro.kernels.harness import Epilogue  # noqa: F401  (public alias)
 from repro.kernels.harness import words_view  # noqa: F401  (public alias)
 
-BACKENDS = ("xla", "pallas", "oracle", "scalar")
+BACKENDS = ("xla", "pallas", "oracle")
 
 
 @functools.partial(jax.jit, static_argnames=("codec", "width", "chunk_elems",

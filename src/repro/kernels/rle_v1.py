@@ -3,8 +3,8 @@
 Everything below is exactly what the paper's §IV-A framework claim says a
 codec author writes: a Phase-1 header parse and a Phase-2 value expression.
 The while-loop group-table scaffolding, the scatter/cumsum/gather all-thread
-expansion, the §V-E single-thread ablation, and the Pallas chunk-per-cell
-wrapper all live in ``kernels/harness.py``; the host encoder is
+expansion, the group-serial oracle, and the Pallas chunk-per-cell wrapper
+all live in ``kernels/harness.py``; the host encoder is
 ``encoders.compress_rle_v1``; the sequential oracle stays in
 ``kernels/ref.py``.
 

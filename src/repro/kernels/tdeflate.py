@@ -22,9 +22,10 @@ Chunk-level parallelism comes from the harness's generic chunk-per-grid-cell
 ``pallas_call`` wrapper, exactly CODAG's warp-per-chunk provisioning.  LZ
 copies read earlier output, so this codec plugs its own chunk bodies into the
 ``DecodeSpec`` interface instead of a ``TwoPhaseSpec``; the deflate
-base/extra tables ride the wrapper's broadcast-constant lane.  The §V-E
-scalar body and the serial oracle (``ref.decode_tdeflate_impl``, Table II
-``memcpy``) execute the tokens one at a time.
+base/extra tables ride the wrapper's broadcast-constant lane.  The serial
+oracle (``ref.decode_tdeflate_impl``, Table II ``memcpy``) executes the
+tokens one at a time; it is the reference the other backends are checked
+against.
 """
 from __future__ import annotations
 
@@ -226,65 +227,6 @@ def decode_chunk(words: jnp.ndarray, lut_lsym: jnp.ndarray,
         return jnp.where(idx < jnp.minimum(ends[-1], out_len_dyn), out, 0)
 
 
-def decode_chunk_scalar(words, lut_lsym, lut_lbits, lut_dsym, lut_dbits,
-                        out_len_dyn, out_len_max: int) -> jnp.ndarray:
-    """§V-E single-thread baseline: one output byte per loop step (match
-    copies proceed byte-by-byte through a scalar back-reference cursor)."""
-    def cond(s):
-        pos, done = s[1], s[6]
-        return jnp.logical_and(~done, pos < out_len_dyn)
-
-    def body(s):
-        bs, pos, rem, src, is_m, buf, done = s
-        need = rem == 0
-        # decode next token only when needed
-        v = st.peek_bits(bs, enc.MAX_CODE_BITS)
-        sym = jnp.take(lut_lsym, v.astype(jnp.int32), mode="clip")
-        nb = jnp.take(lut_lbits, v.astype(jnp.int32), mode="clip")
-        is_lit = (sym < 256) & (nb > 0)
-        is_eob = (sym == 256) | (nb == 0)
-        lc = jnp.clip(sym - 257, 0, 28)
-        bs_m = st.skip_bits(bs, nb)
-        eb = jnp.take(LEN_EXTRA, lc)
-        length = jnp.take(LEN_BASE, lc) + st.peek_bits(bs_m, eb).astype(jnp.int32)
-        bs_m = st.skip_bits(bs_m, eb)
-        dv = st.peek_bits(bs_m, enc.MAX_CODE_BITS)
-        dsym = jnp.take(lut_dsym, dv.astype(jnp.int32), mode="clip")
-        dnb = jnp.take(lut_dbits, dv.astype(jnp.int32), mode="clip")
-        bs_m = st.skip_bits(bs_m, dnb)
-        deb = jnp.take(DIST_EXTRA, dsym)
-        dist = jnp.take(DIST_BASE, dsym) + st.peek_bits(bs_m, deb).astype(jnp.int32)
-        bs_m = st.skip_bits(bs_m, deb)
-        bs_lit = st.skip_bits(bs, nb)
-        new_is_m = (sym > 256) & (nb > 0)
-        rem = jnp.where(need, jnp.where(is_lit, 1, length), rem)
-        is_m = jnp.where(need, new_is_m, is_m)
-        src = jnp.where(need, jnp.where(new_is_m, pos - dist, 0), src)
-        lit_byte = (sym & 0xFF).astype(jnp.uint8)
-        copy_byte = jnp.take(buf, src, mode="clip")
-        # freeze token decode state when mid-copy
-        bs = jax.tree.map(
-            lambda new_m, new_l, old: jnp.where(
-                need, jnp.where(new_is_m, new_m, new_l), old),
-            bs_m, bs_lit, bs)
-        done = done | (need & is_eob)
-        emit = ~(need & is_eob)
-        wpos = jnp.where(emit, pos, out_len_max + 8)
-        buf = buf.at[wpos].set(jnp.where(is_m, copy_byte,
-                                         jnp.where(need, lit_byte,
-                                                   copy_byte)))
-        pos = pos + jnp.where(emit, 1, 0)
-        rem = rem - jnp.where(emit, 1, 0)
-        src = src + 1
-        return bs, pos, rem, src, is_m, buf, done
-
-    init = (st.bitstream(words), jnp.int32(0), jnp.int32(0), jnp.int32(0),
-            jnp.bool_(False), jnp.zeros((out_len_max + 16,), jnp.uint8),
-            jnp.bool_(False))
-    s = lax.while_loop(cond, body, init)
-    return s[5][:out_len_max]
-
-
 # --------------------------------------------------------------------------
 # registry plumbing: device operands + the DecodeSpec bodies
 # --------------------------------------------------------------------------
@@ -303,11 +245,6 @@ def _body(inputs, consts, out_len, *, chunk_elems, width, bits):
     words, ls, lb, ds, db = inputs
     return decode_chunk(words, ls, lb, ds, db, out_len, chunk_elems,
                         tables=consts or None)
-
-
-def _body_scalar(inputs, consts, out_len, *, chunk_elems, width, bits):
-    words, ls, lb, ds, db = inputs
-    return decode_chunk_scalar(words, ls, lb, ds, db, out_len, chunk_elems)
 
 
 def _body_oracle(inputs, consts, out_len, *, chunk_elems, width, bits):
@@ -329,7 +266,6 @@ CODEC = registry.register(registry.Codec(
     encode=enc.compress_tdeflate,
     decode=harness.DecodeSpec(
         body=_body,
-        body_scalar=_body_scalar,
         body_oracle=_body_oracle,
         chunk_inputs=_chunk_inputs,
         consts=lambda: (LEN_EXTRA, LEN_BASE, DIST_EXTRA, DIST_BASE),

@@ -8,7 +8,8 @@ group, verified by monkeypatching ``ops.decode``.
 import numpy as np
 import pytest
 
-from repro.core import api, batch, encoders as enc, format as fmt, registry
+from repro.core import api, encoders as enc, format as fmt, registry
+from repro.core import plan as plan_mod
 from repro.core.engine import CodagEngine, EngineConfig
 from repro.kernels import ops
 
@@ -103,7 +104,7 @@ def test_scatter_back_ordering():
 
 def test_empty_and_single_blob_edges(counted):
     assert api.decompress_many([]) == []
-    assert batch.decompress_blobs([]) == []
+    assert plan_mod.decompress_blobs([]) == []
     assert len(counted) == 0
 
     arr = _runs_u32(512)
@@ -117,7 +118,7 @@ def test_plan_structure_and_merged_table():
     blobs = [enc.compress(_runs_u32(600), fmt.RLE_V1, 512) for _ in range(3)]
     blobs.append(enc.compress(RNG.integers(0, 9, 300).astype(np.uint8),
                               fmt.RLE_V1, 512))
-    plan = batch.BatchPlan.build(blobs)
+    plan = plan_mod.DecodePlan.build(blobs)
     assert plan.num_dispatches == 2
     g = plan.groups[0]
     assert g.blob_ids == (0, 1, 2)
@@ -154,10 +155,10 @@ def test_batched_engine_config_respected(counted):
     arrays = [_runs_u32(700), _runs_u32(900)]
     cas = api.compress_many(arrays, fmt.RLE_V2, chunk_bytes=512)
     outs = api.decompress_many(cas, CodagEngine(EngineConfig(
-        unit="block", n_units=2)))
+        backend="oracle")))
     for arr, out in zip(arrays, outs):
         assert np.array_equal(out, arr)
-    assert len(counted) == 1  # block unit still traces one decode
+    assert [c["backend"] for c in counted] == ["oracle"]
 
 
 def test_mixed_arrays_cover_registry():

@@ -42,16 +42,14 @@ def datasets():
 
 
 ENGINES = {
-    "warp_xla": EngineConfig(unit="warp", backend="xla"),
-    "warp_pallas": EngineConfig(unit="warp", backend="pallas"),
-    "oracle": EngineConfig(unit="warp", backend="oracle"),
-    "single_thread": EngineConfig(unit="warp", all_thread=False),
-    "block_unit": EngineConfig(unit="block", n_units=3),
+    "warp_xla": EngineConfig(backend="xla"),
+    "warp_pallas": EngineConfig(backend="pallas"),
+    "oracle": EngineConfig(backend="oracle"),
 }
 
 
 # warp_xla + oracle stay in the fast tier; the interpret-mode Pallas engine
-# and the provisioning ablations are several seconds per case -> nightly.
+# is several seconds per case -> nightly.
 _FAST_ENGINES = ("warp_xla", "oracle")
 
 

@@ -75,7 +75,7 @@ def test_tdeflate_property_bytes(data):
                hst.lists(hst.integers(0, 255), min_size=1, max_size=400)),
     min_size=0, max_size=6))
 def test_batched_matches_per_blob_property(items):
-    """Batched decode (core.batch) is bit-exact vs per-array decompress."""
+    """Batched decode (core.plan) is bit-exact vs per-array decompress."""
     arrays = [np.asarray(data, np.uint8) for _, data in items]
     cas = api.compress_many(arrays, [c for c, _ in items], chunk_bytes=256)
     outs = api.decompress_many(cas, _eng)
@@ -204,7 +204,7 @@ def test_service_adversarial_matches_direct(items):
     cas = api.compress_many(arrays, [c for c, _ in items], chunk_bytes=250)
     outs = api.decompress_many(cas)           # default-service path
     cached = api.decompress_many(cas, service=_cached_service())
-    direct = api.decompress_many(cas, _eng)   # synchronous BatchPlan path
+    direct = api.decompress_many(cas, _eng)   # synchronous DecodePlan path
     for arr, out, hit, ref in zip(arrays, outs, cached, direct):
         assert np.array_equal(out, arr)
         assert np.array_equal(hit, arr)

@@ -8,7 +8,7 @@ encoded blob (``server.blob_digest``).  The suite locks two guarantees:
     digest bit-for-bit (format drift cannot slip in silently; regenerate
     with ``scripts/make_vectors.py`` ONLY on an intentional format change);
   * decoder conformance — every backend (xla / oracle in the fast tier,
-    pallas / scalar nightly) decodes every vector back to the original
+    pallas nightly) decodes every vector back to the original
     bytes exactly.
 
 A codec present in ``registry.names()`` with no committed vectors fails
@@ -28,12 +28,11 @@ from repro.kernels import ops
 VEC_DIR = Path(__file__).parent / "vectors"
 ALL_CODECS = registry.names()
 
-# interpret-mode pallas and the single-thread ablation are seconds per
-# case -> nightly tier, same split as test_codecs.py.
+# interpret-mode pallas is seconds per case -> nightly tier, same split as
+# test_codecs.py.
 BACKENDS = [
     "xla", "oracle",
     pytest.param("pallas", marks=pytest.mark.slow),
-    pytest.param("scalar", marks=pytest.mark.slow),
 ]
 
 

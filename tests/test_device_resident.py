@@ -19,8 +19,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import api, batch, registry, transfers
+from repro.core import api, registry, transfers
 from repro.core import format as fmt
+from repro.core import plan as plan_mod
 from repro.core.engine import CodagEngine, EngineConfig
 from repro.core.server import DecompressionService
 from repro.kernels import dequant_matmul as dqm
@@ -119,7 +120,7 @@ def test_ragged_scatter_indices():
 def test_batchplan_carries_scatter():
     blobs = [api.compress(_demo("rle_v2", n), "rle_v2",
                           chunk_bytes=1024).blobs[0] for n in (1025, 4097)]
-    plan = batch.BatchPlan.build(blobs)
+    plan = plan_mod.DecodePlan.build(blobs)
     assert all(len(g.scatter) == len(g.blob_ids) for g in plan.groups)
     plan.stage()
     outs = plan.execute_device(ENGINE)
@@ -155,21 +156,6 @@ def test_epilogue_dequant_scale_zero():
                                 epilogue_operands=operands)
     assert out.dtype == jnp.float32
     want = (arr.astype(np.float32) - 3.0) * 0.25
-    np.testing.assert_allclose(np.asarray(out), want, rtol=1e-6)
-
-
-def test_epilogue_block_unit_engine():
-    """Scalar epilogue operands replicate via closure on the block-unit
-    (RAPIDS-ablation) engine instead of breaking the lax.scan leading-dim
-    contract."""
-    arr = _demo("rle_v2", 3000)
-    ca = api.compress(arr, "rle_v2", chunk_bytes=512)
-    block = CodagEngine(EngineConfig(unit="block", n_units=4))
-    epi = Epilogue(scale_key="epi_s", zero_key="epi_z")
-    operands = {"epi_s": np.float32(0.5), "epi_z": np.uint32(1)}
-    [out] = api.decompress_many([ca], block, device_out=True, epilogue=epi,
-                                epilogue_operands=operands)
-    want = (arr.astype(np.float32) - 1.0) * 0.5
     np.testing.assert_allclose(np.asarray(out), want, rtol=1e-6)
 
 
@@ -239,7 +225,7 @@ def test_device_out_decode_zero_host_transfers():
     # while the host path funnels exactly one d2h per fused group
     with transfers.count_host_transfers() as c:
         api.decompress_many(cas, ENGINE)
-    assert c["d2h"] == batch.BatchPlan.build(
+    assert c["d2h"] == plan_mod.DecodePlan.build(
         [b for ca in cas for b in ca.blobs]).num_dispatches
 
 
@@ -277,13 +263,13 @@ def test_no_host_transfer_gate():
 
 
 def test_staged_plan_reuse_under_guard():
-    """A pre-staged BatchPlan replays decode→scatter→epilogue with zero
+    """A pre-staged DecodePlan replays decode→scatter→epilogue with zero
     transfers in either direction (the steady-state serving pattern)."""
     rng = np.random.default_rng(11)
     q = rng.integers(-8, 8, (128, 128)).astype(np.int8)
     ca = dqm.compress_weights(q, zero_point=8)
     epi, operands = dqm.weight_epilogue(8)
-    plan = batch.BatchPlan.build(ca.blobs).stage()
+    plan = plan_mod.DecodePlan.build(ca.blobs).stage()
     plan.execute_device(ENGINE, epilogue=epi,
                         epilogue_operands=operands)[0].block_until_ready()
     with transfers.no_host_transfers():

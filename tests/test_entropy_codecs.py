@@ -26,13 +26,12 @@ from repro.kernels import lzss as lz
 
 RNG = np.random.default_rng(17)
 
-# xla / oracle / scalar cover the three decode disciplines cheaply; the
-# interpret-mode Pallas engine is exercised once per codec in the tuned-knob
-# test below.
+# xla and oracle cover the production decode and its reference cheaply;
+# the interpret-mode Pallas engine is exercised once per codec in the
+# tuned-knob test below.
 ENGINES = {
-    "xla": EngineConfig(unit="warp", backend="xla"),
-    "oracle": EngineConfig(unit="warp", backend="oracle"),
-    "scalar": EngineConfig(unit="warp", all_thread=False),
+    "xla": EngineConfig(backend="xla"),
+    "oracle": EngineConfig(backend="oracle"),
 }
 
 

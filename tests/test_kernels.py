@@ -90,7 +90,8 @@ def test_bitpack_kernel_vs_oracle(bits, n):
 
 
 def test_scalar_variant_matches_vectorized():
-    """§V-E ablation implementations agree with the two-phase kernels."""
+    """The two-phase kernels agree with the group-serial oracle on uint16
+    data in 777-byte chunks."""
     for codec in (fmt.RLE_V1, fmt.RLE_V2, fmt.DBP):
         arr = _gen("mixed", 2000, np.uint16)
         blob = enc.compress(arr, codec, chunk_bytes=777)
@@ -98,7 +99,7 @@ def test_scalar_variant_matches_vectorized():
         a = ops.decode(dev, codec=codec, width=blob.width,
                        chunk_elems=blob.chunk_elems, backend="xla")
         b = ops.decode(dev, codec=codec, width=blob.width,
-                       chunk_elems=blob.chunk_elems, backend="scalar")
+                       chunk_elems=blob.chunk_elems, backend="oracle")
         for i in range(blob.num_chunks):
             ol = int(blob.out_lens[i])
             np.testing.assert_array_equal(np.asarray(a)[i, :ol],
@@ -106,13 +107,14 @@ def test_scalar_variant_matches_vectorized():
 
 
 def test_tdeflate_scalar_matches():
+    """tdeflate's two-phase decode agrees with the serial oracle."""
     arr = _gen("mixed", 1500, np.uint8)
     blob = enc.compress(arr, fmt.TDEFLATE, chunk_bytes=600)
     dev = {k: jnp.asarray(v) for k, v in blob.to_device().items()}
     a = ops.decode(dev, codec=fmt.TDEFLATE, width=1,
                    chunk_elems=blob.chunk_elems, backend="xla")
     b = ops.decode(dev, codec=fmt.TDEFLATE, width=1,
-                   chunk_elems=blob.chunk_elems, backend="scalar")
+                   chunk_elems=blob.chunk_elems, backend="oracle")
     for i in range(blob.num_chunks):
         ol = int(blob.out_lens[i])
         np.testing.assert_array_equal(np.asarray(a)[i, :ol],

@@ -14,7 +14,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import api, batch, format as fmt, server, transfers
+from repro.core import api, format as fmt, server, transfers
 from repro.core import engine as engine_mod
 from repro.core import plan as plan_mod
 from repro.core.engine import CodagEngine, EngineConfig
@@ -54,10 +54,10 @@ def _ops_decode_calls(module):
 
 
 def test_no_ops_decode_call_sites_outside_plan():
-    """ISSUE-5 acceptance gate: engine/batch/api/server contain ZERO
-    ``ops.decode*`` call sites — the plan executor is the only module that
-    lowers to the kernel dispatch layer."""
-    for mod in (engine_mod, batch, api, server):
+    """Acceptance gate: engine/api/server contain ZERO ``ops.decode*``
+    call sites — the plan executor is the only module that lowers to the
+    kernel dispatch layer."""
+    for mod in (engine_mod, api, server):
         assert _ops_decode_calls(mod) == [], mod.__name__
     # and plan.py itself still has them (the gate is not vacuous)
     assert _ops_decode_calls(plan_mod)
@@ -83,7 +83,7 @@ def test_every_entry_path_lowers_through_plan(entry):
         elif entry == "engine_device":
             out = np.asarray(ENGINE.decompress_device(ca.blobs[0]))
         elif entry == "batch_plan":
-            out = batch.BatchPlan.build(ca.blobs).execute(ENGINE)[0]
+            out = plan_mod.DecodePlan.build(ca.blobs).execute(ENGINE)[0]
         else:
             with server.DecompressionService(ENGINE) as svc:
                 out = svc.decode(ca.blobs[0])
@@ -92,25 +92,6 @@ def test_every_entry_path_lowers_through_plan(entry):
     assert len(lowered) == len(dispatched)
     assert [c["codec"] for c in lowered] == \
            [c["codec"] for c in dispatched]
-
-
-def test_block_unit_lowering_matches_warp():
-    """The block (RAPIDS-ablation) provisioning unit lives in the plan's
-    dispatch stage now — one lowered dispatch, bit-exact output."""
-    arr = _runs(3000, seed=5)
-    ca = api.compress(arr, fmt.RLE_V2, chunk_bytes=512)
-    block = CodagEngine(EngineConfig(unit="block", n_units=3))
-    with plan_mod.count_lowered() as lowered:
-        out = api.decompress(ca, block)
-    assert np.array_equal(out, arr)
-    assert len(lowered) == 1 and lowered[0]["unit"] == "block"
-
-
-def test_batchplan_is_decodeplan_alias():
-    """The batch scheduler's machinery lives in exactly one module."""
-    assert batch.BatchPlan is plan_mod.DecodePlan
-    assert batch.GroupPlan is plan_mod.PlanGroup
-    assert batch.decompress_blobs is plan_mod.decompress_blobs
 
 
 # --------------------------------------------------------------------------

@@ -2,7 +2,7 @@
 
 Covers:
   * the registry-completeness contract CI gates on (every registered codec
-    has full hooks and appears in the bench-smoke + ablation matrices),
+    has full hooks and appears in the bench-smoke matrix),
   * the reentrant ``ops.count_dispatches`` (nested contexts),
   * the ``ops.words_view`` zero-padding fix for odd-width rows.
 """
@@ -44,11 +44,10 @@ def test_registered_codec_is_complete(name):
 
 
 def test_bench_smoke_matrices_cover_registry():
-    """CI gate: a registered codec missing from the bench-smoke or ablation
-    matrix fails here (and in scripts/check_registry.py)."""
-    from benchmarks import ablations, batched
+    """CI gate: a registered codec missing from the bench-smoke matrix
+    fails here (and in scripts/check_registry.py)."""
+    from benchmarks import batched
     assert set(batched.codec_matrix()) == set(registry.names())
-    assert set(ablations.codec_matrix()) == set(registry.names())
 
 
 def test_unknown_codec_raises():
